@@ -16,9 +16,9 @@ profiling).
 
 ``--n-devices N`` forces N host devices (setting
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` before jax
-initializes — a no-op if jax is already live, e.g. under the run.py
-aggregator, in which case the available device count is used) and runs
-the batched engine on an N-wide 'clients' mesh.  ``--sweep-clients
+initializes; the bench fails if jax is already live with fewer devices,
+e.g. under the run.py aggregator) and runs the batched engine on an
+N-wide 'clients' mesh.  ``--sweep-clients
 8,16,32,64`` adds the ROADMAP scaling sweep: for each client count C the
 batched engine runs once on a single device and once on the mesh,
 reporting round wall-time vs device count.
@@ -32,7 +32,7 @@ import argparse
 import sys
 import time
 
-from benchmarks.hostdev import clamp_to_visible, force_host_devices
+from benchmarks.hostdev import force_host_devices, require_visible
 
 
 def main(argv=()):
@@ -71,7 +71,6 @@ def main(argv=()):
     if args.n_devices > 1 and "jax" not in sys.modules:
         force_host_devices(args.n_devices)
 
-    import jax
     import numpy as np
 
     from benchmarks.common import emit, get_task
@@ -80,7 +79,7 @@ def main(argv=()):
 
     if args.backend not in BACKENDS:
         ap.error(f"--backend must be one of {sorted(BACKENDS)}")
-    n_dev = clamp_to_visible(args.n_devices, "federated_round")
+    n_dev = require_visible(args.n_devices, "federated_round")
 
     def _run(engine, *, rounds, maxiter, clients=args.clients,
              devices=None, n_qubits=4):
@@ -148,9 +147,8 @@ def main(argv=()):
         # at growing client counts.  Cold+warm per point; the warm number
         # is the steady-state round time the mesh is judged on.
         sweep = [int(c) for c in args.sweep_clients.split(",") if c]
-        mesh_w = n_dev if n_dev > 1 else len(jax.devices())
         for C in sweep:
-            for devices in (None, mesh_w) if mesh_w > 1 else (None,):
+            for devices in (None, n_dev) if n_dev > 1 else (None,):
                 _run("batched", rounds=1, maxiter=maxiter, clients=C,
                      devices=devices)                        # compile
                 wall, res = _run("batched", rounds=rounds,

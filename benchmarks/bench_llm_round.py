@@ -24,7 +24,7 @@ import argparse
 import sys
 import time
 
-from benchmarks.hostdev import clamp_to_visible, force_host_devices
+from benchmarks.hostdev import force_host_devices, require_visible
 
 
 def main(argv=()):
@@ -59,7 +59,7 @@ def main(argv=()):
     from repro.core.llm_client import run_sequential_stage, task_llm_config
     from repro.models import model as M
 
-    n_dev = clamp_to_visible(args.n_devices, "llm_round")
+    n_dev = require_visible(args.n_devices, "llm_round")
 
     steps = args.steps or (8 if args.smoke else 30)
     per_client = args.train_size // args.clients if args.train_size \
@@ -124,10 +124,9 @@ def main(argv=()):
 
     if args.sweep_clients:
         sweep = [int(c) for c in args.sweep_clients.split(",") if c]
-        mesh_w = n_dev if n_dev > 1 else len(jax.devices())
         for C in sweep:
             task, cfg, base = make(C)
-            for devs in (None, mesh_w) if mesh_w > 1 else (None,):
+            for devs in (None, n_dev) if n_dev > 1 else (None,):
                 run_batched(task, cfg, base, devices=devs)     # compile
                 wall, _ = run_batched(task, cfg, base, devices=devs)
                 d = devs or 1

@@ -28,7 +28,7 @@ import argparse
 import sys
 import time
 
-from benchmarks.hostdev import clamp_to_visible, force_host_devices
+from benchmarks.hostdev import force_host_devices, require_visible
 
 
 def main(argv=()):
@@ -73,7 +73,7 @@ def main(argv=()):
     if args.backend not in backend_mod.BACKENDS:
         ap.error(f"--backend must be one of "
                  f"{sorted(backend_mod.BACKENDS)}")
-    n_dev = clamp_to_visible(args.n_devices, "population")
+    n_dev = require_visible(args.n_devices, "population")
 
     c_pop = args.c_pop or (48 if args.smoke else 1024)
     c_round = args.c_round or (8 if args.smoke else 32)

@@ -1,14 +1,33 @@
-"""Shared benchmark harness: tasks, timing, CSV/JSON emission."""
+"""Shared benchmark harness: tasks, timing, CSV/JSON emission, and the
+persistent compilation cache every bench process shares."""
 from __future__ import annotations
 
 import json
+import os
 import time
 from pathlib import Path
 from typing import Dict, List
 
+import jax
+
 from repro.data.tasks import build_task
 
-OUT_DIR = Path(__file__).resolve().parent.parent / "experiments" / "bench"
+ROOT = Path(__file__).resolve().parent.parent
+OUT_DIR = ROOT / "experiments" / "bench"
+
+
+def use_compile_cache() -> str:
+    """Keep compiled programs across processes.  ``JAX_COMPILATION_CACHE_DIR``
+    wins when set (jax reads it itself); otherwise the cache is the fixed
+    ``.jax_cache/`` of this checkout — a fixed path, because the path is
+    part of the cache key."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          str(ROOT / ".jax_cache"))
+    return jax.config.jax_compilation_cache_dir
+
+
+use_compile_cache()
 
 _TASK_CACHE: Dict = {}
 

@@ -19,13 +19,15 @@ def force_host_devices(n: int) -> None:
         os.environ["XLA_FLAGS"] = (cur + " " + flag).strip()
 
 
-def clamp_to_visible(n_dev: int, bench: str) -> int:
-    """Clamp a requested mesh width to the devices jax actually exposes
-    (jax may already be initialized, e.g. under the run.py aggregator),
-    emitting the bench's standard warning row when it does."""
+def require_visible(n_dev: int, bench: str) -> int:
+    """Return the requested mesh width, or fail when jax exposes fewer
+    devices (it may have initialized before ``force_host_devices``, e.g.
+    under the run.py aggregator): a bench never measures a smaller mesh
+    than it was asked for."""
     import jax                       # initialized by now — safe to touch
     if n_dev > len(jax.devices()):
-        print(f"{bench}/_warn,,wanted {n_dev} devices, platform exposes "
-              f"{len(jax.devices())} (jax initialized early?) — clamping")
-        return len(jax.devices())
+        raise SystemExit(
+            f"{bench}: wanted {n_dev} devices, the platform exposes "
+            f"{len(jax.devices())} (jax initialized before the device "
+            f"count could be forced?)")
     return n_dev

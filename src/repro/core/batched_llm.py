@@ -88,8 +88,9 @@ def _build_llm_round_fn(cfg, n_labels: int, lr: float, batch_size: int,
     """Jitted fine-tuning stage → (adapters, opt, a_g, losses, f1,
     teacher, last_train_loss).  Static config closed over; every
     per-round quantity (stacks, keys, weights) is a traced input."""
-    train_step = M.make_train_step(cfg, n_microbatches=1, lr=lr,
-                                   opts=M.FwdOptions(remat=False))
+    # the model's default per-layer remat: at Llama-3.2-1B widths the
+    # saved activations of C vmapped clients would not fit one chip
+    train_step = M.make_train_step(cfg, n_microbatches=1, lr=lr)
     vstep = jax.vmap(train_step, in_axes=(None, 0, 0, 0))
 
     def eval_one(params, adp, toks, labs, rmask):

@@ -412,14 +412,12 @@ def get_fused_program(spec, backend, *, lam, mu, use_llm, optimizer,
     """Module-wide cache, like ``batched_engine.get_round_fn``: fresh
     driver instances with the same static config reuse the compiled
     scan (population stacks and θ_g are traced arguments)."""
-    mesh_key = (None if mesh is None
-                else tuple(int(d.id) for d in mesh.devices.flat))
     key = (spec, backend, int(backend.shots), float(lam), float(mu),
            bool(use_llm), optimizer, int(max_iter), regulation,
            int(maxiter_cap), float(select_frac), float(epsilon),
            int(patience), int(n_rounds), bool(early_stop), int(c_pop),
            int(c_pad), None if c_round is None else int(c_round),
-           float(dropout), mesh_key)
+           float(dropout), mesh)
     if key not in _FUSED_CACHE:
         _FUSED_CACHE[key] = _build_fused_program(
             spec, backend, lam=lam, mu=mu, use_llm=use_llm,
@@ -604,23 +602,26 @@ class FusedRoundDriver:
             epsilon=epsilon, patience=patience, n_rounds=int(n_rounds),
             early_stop=early_stop, c_pop=C, c_pad=c_pad, c_round=c_round,
             dropout=float(dropout))
-        self._program = get_fused_program(spec, backend, mesh=self._mesh,
-                                          **self._cfg)
+        self.program = get_fused_program(spec, backend, mesh=self._mesh,
+                                         **self._cfg)
         self._fwd = None          # host-reference lazies
         self._local_jit = None
 
     # -- fused path ---------------------------------------------------------
-    def run(self, theta_g) -> FusedRunOutput:
-        """All R rounds as one program execution; one device→host
-        transfer for the whole run's outputs."""
+    def program_args(self, theta_g) -> tuple:
+        """The fused program's arguments for a run from ``theta_g``."""
         th = jnp.asarray(theta_g, jnp.float32)
         if self._mesh is not None:
             th = shd.put_replicated(self._mesh, th)
-        out = self._program(th, self._budgets0, self._last0, self._cum0,
-                            self._qX, self._qy, self._mask, self._teacher,
-                            self._deltas, self._weights, self._evaltime,
-                            self._llm, self._val_qX, self._val_qy,
-                            self._test_qX, self._test_qy, self._base_key)
+        return (th, self._budgets0, self._last0, self._cum0, self._qX,
+                self._qy, self._mask, self._teacher, self._deltas,
+                self._weights, self._evaltime, self._llm, self._val_qX,
+                self._val_qy, self._test_qX, self._test_qy, self._base_key)
+
+    def run(self, theta_g) -> FusedRunOutput:
+        """All R rounds as one program execution; one device→host
+        transfer for the whole run's outputs."""
+        out = self.program(*self.program_args(theta_g))
         host = jax.device_get(out)
         return FusedRunOutput(**{k: np.asarray(v) for k, v in host.items()})
 

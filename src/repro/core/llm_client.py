@@ -122,7 +122,9 @@ def label_logits(cfg, params: Dict, adapters: Dict, tokens: jnp.ndarray,
     head = (params["embed"].T if cfg.tie_embeddings
             else params["lm_head"])
     label_head = head[:, -n_labels:].astype(jnp.float32)
-    logits = h.astype(jnp.float32) @ label_head
+    # full f32 (the TPU default would round both operands to bfloat16)
+    logits = jnp.dot(h.astype(jnp.float32), label_head,
+                     precision=jax.lax.Precision.HIGHEST)
     gold_tok = jnp.take_along_axis(labels, pos[:, None], axis=1)[:, 0]
     gold = jnp.clip(gold_tok - (cfg.vocab_size - n_labels), 0,
                     n_labels - 1)
@@ -184,8 +186,7 @@ class LLMClient:
         self.adapters = M.init_adapters(
             cfg, llm_key(key, client_id, LLM_INIT_STEP), base_params)
         self.opt_state = adamw.init(self.adapters)
-        self._step = M.get_train_step(cfg, n_microbatches=1, lr=lr,
-                                      opts=M.FwdOptions(remat=False))
+        self._step = M.get_train_step(cfg, n_microbatches=1, lr=lr)
         self._n_steps = 0                 # global step counter (contract)
 
     # -- fine-tuning (round 1 / periodic refresh) ---------------------------

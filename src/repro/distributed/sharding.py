@@ -222,32 +222,28 @@ def cache_specs(cache, axis_names, batch: int, axis_sizes=None):
     return jax.tree.map(leaf, cache)
 
 
+def _mesh_sizes():
+    """Axis sizes of the mesh set by ``jax.set_mesh``; empty when none is
+    set — the one case the helpers below treat as "no sharding"."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return dict(zip(mesh.axis_names, mesh.axis_sizes))
+
+
 def constrain(x, spec: P):
-    """with_sharding_constraint if an abstract mesh is available, else no-op.
-    Axes that do not exist on the mesh or do not divide the dim are
-    dropped (graceful degradation on small smoke meshes)."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or not mesh.axis_names:
-            return x
-        fspec = _filter_axes(spec, mesh.axis_names)
-        sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
-        fspec = _fit_divisibility(fspec, x.shape, sizes)
-        return jax.lax.with_sharding_constraint(x, fspec)
-    except Exception:
+    """with_sharding_constraint under a mesh, a no-op without one.  Axes
+    that do not exist on the mesh or do not divide the dim are dropped
+    (small smoke meshes)."""
+    sizes = _mesh_sizes()
+    if not sizes:
         return x
+    fspec = _fit_divisibility(_filter_axes(spec, tuple(sizes)), x.shape,
+                              sizes)
+    return jax.lax.with_sharding_constraint(x, fspec)
 
 
 def mesh_axis_size(name: str) -> int:
-    """Size of a mesh axis under the current abstract mesh (1 if absent)."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None:
-            return 1
-        sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
-        return int(sizes.get(name, 1))
-    except Exception:
-        return 1
+    """Size of a mesh axis under the current mesh (1 if absent)."""
+    return int(_mesh_sizes().get(name, 1))
 
 
 def packed_gather_spec(name: str) -> P:

@@ -12,6 +12,7 @@ cheap iota comparison (no gather).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -67,8 +68,12 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     "causal", "window", "scale", "bq", "bk", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     scale: float = None, bq: int = 128, bk: int = 128,
-                    interpret: bool = True):
-    """q (B,H,S,D), k/v (B,H,S_k,D) already GQA-expanded → (B,H,S,D)."""
+                    interpret: Optional[bool] = None):
+    """q (B,H,S,D), k/v (B,H,S_k,D) already GQA-expanded → (B,H,S,D).
+
+    ``interpret=None`` interprets on the CPU backend only."""
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
     B, H, S, D = q.shape
     Sk = k.shape[2]
     scale = scale or (D ** -0.5)

@@ -15,6 +15,7 @@ VMEM at defaults (bm=bn=128, K≤8192, bf16): ~4.3 MiB — fits v5e's 16 MiB.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -35,8 +36,12 @@ def _kernel(x_ref, w_ref, a_ref, b_ref, o_ref, *, scale: float):
 @functools.partial(jax.jit,
                    static_argnames=("scale", "bm", "bn", "interpret"))
 def lora_matmul(x, w, a, b, *, scale: float, bm: int = 128, bn: int = 128,
-                interpret: bool = True):
-    """x (M,K) @ w (K,N) + scale·(x@a (K,r))@b (r,N) → (M,N)."""
+                interpret: Optional[bool] = None):
+    """x (M,K) @ w (K,N) + scale·(x@a (K,r))@b (r,N) → (M,N).
+
+    ``interpret=None`` interprets on the CPU backend only."""
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
     M, K = x.shape
     _, N = w.shape
     r = a.shape[1]

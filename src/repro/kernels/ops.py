@@ -1,13 +1,12 @@
 """Public jit'd wrappers over the Pallas kernels.
 
-On this CPU container every kernel executes with ``interpret=True``
-(Pallas interpreter — bit-accurate kernel-body semantics); on TPU the same
-call sites pass ``interpret=False`` and compile to Mosaic.  ``INTERPRET``
-flips the default globally.
+Each kernel takes ``interpret=None`` by default and resolves it when it
+is traced: the Pallas interpreter on the CPU backend, Mosaic on a TPU.
+``statevector_gate`` has no Mosaic lowering and raises off the CPU
+unless ``interpret=True`` is passed.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels import distill_kl as _kl
@@ -16,21 +15,16 @@ from repro.kernels import int4_matmul as _i4
 from repro.kernels import lora_matmul as _lm
 from repro.kernels import statevector_gates as _svg
 
-INTERPRET = jax.default_backend() == "cpu"
-
 
 def lora_matmul(x, w, a, b, *, scale: float, **kw):
-    kw.setdefault("interpret", INTERPRET)
     return _lm.lora_matmul(x, w, a, b, scale=scale, **kw)
 
 
 def int4_matmul(x, packed, scales, *, qblock: int = 64, **kw):
-    kw.setdefault("interpret", INTERPRET)
     return _i4.int4_matmul(x, packed, scales, qblock=qblock, **kw)
 
 
 def distill_kl(teacher_probs, student_logits, **kw):
-    kw.setdefault("interpret", INTERPRET)
     return _kl.distill_kl(teacher_probs, student_logits, **kw)
 
 
@@ -39,13 +33,9 @@ def distill_kl_mean(teacher_probs, student_logits, **kw):
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0, **kw):
-    kw.setdefault("interpret", INTERPRET)
     return _fa.flash_attention(q, k, v, causal=causal, window=window, **kw)
 
 
 def statevector_gate(psi_re, psi_im, g_re, g_im, idx0, idx1, cmask, **kw):
-    # interpret-only for now: the kernel body's dynamic gather/scatter on
-    # idx0/idx1 does not lower through Mosaic yet (ROADMAP open item)
-    kw.setdefault("interpret", True)
     return _svg.statevector_gate(psi_re, psi_im, g_re, g_im,
                                  idx0, idx1, cmask, **kw)

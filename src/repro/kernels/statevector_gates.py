@@ -19,6 +19,7 @@ Grid: (B/bb,).  Blocks: planes (bb, N), gates (bb, 2, 2), metadata
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -59,8 +60,20 @@ def _kernel(pr_ref, pi_ref, gr_ref, gi_ref, i0_ref, i1_ref, m_ref,
 
 @functools.partial(jax.jit, static_argnames=("bb", "interpret"))
 def statevector_gate(psi_re, psi_im, g_re, g_im, idx0, idx1, cmask, *,
-                     bb: int = 256, interpret: bool = True):
-    """(B,N)×2 planes, (B,2,2)×2 gate planes, (N/2,) pairing → new planes."""
+                     bb: int = 256, interpret: Optional[bool] = None):
+    """(B,N)×2 planes, (B,2,2)×2 gate planes, (N/2,) pairing → new planes.
+
+    Interpret-only: the body's dynamic gather/scatter on ``idx0``/``idx1``
+    does not lower through Mosaic.  ``interpret=None`` interprets on the
+    CPU backend and raises on any other unless ``interpret=True`` is
+    asked for."""
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    if not interpret:
+        raise NotImplementedError(
+            "statevector_gate does not lower through Mosaic (dynamic "
+            "gather/scatter); on an accelerator use the tape's jnp gate "
+            "apply, or pass interpret=True to run the Pallas interpreter")
     B, N = psi_re.shape
     bb = min(bb, B)
     while B % bb:

@@ -1,21 +1,18 @@
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) and
 extract memory / cost / collective statistics.
 
-The ``os.environ`` line below MUST stay before any other import — jax locks
-the device count on first init, and the production meshes need 512 host
-devices.  Smoke tests and benchmarks never import this module, so they see
-1 device.
+The production meshes need 512 host devices: ``main`` forces that count
+in ``XLA_FLAGS`` before jax's backend first initializes (no import here
+initializes it), so importing this module leaves the environment alone.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch llama3-405b \
       --shape train_4k --mesh single
   PYTHONPATH=src python -m repro.launch.dryrun --all   # every pair
 """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 import argparse
 import json
+import os
 import time
 import traceback
 from pathlib import Path
@@ -232,6 +229,9 @@ def model_flops(cfg, shape) -> float:
 
 
 def main():
+    os.environ["XLA_FLAGS"] = (
+        os.environ.get("XLA_FLAGS", "")
+        + " --xla_force_host_platform_device_count=512").strip()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--shape")

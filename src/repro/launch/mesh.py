@@ -9,16 +9,24 @@ from __future__ import annotations
 import jax
 
 
+def _gspmd_mesh(shape, axes):
+    """A mesh whose axes the partitioner resolves (``AxisType.Auto``):
+    ``sharding.constrain`` places hints for GSPMD, which the explicit
+    axes ``jax.make_mesh`` defaults to would turn into assertions."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """TPU v5e: 16×16 = 256 chips/pod; 2 pods = 512 chips via DCN."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _gspmd_mesh(shape, axes)
 
 
 def make_local_mesh():
     """Single-device mesh for CPU smoke/integration runs."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _gspmd_mesh((1, 1), ("data", "model"))
 
 
 # Hardware constants (TPU v5e) for the roofline model — see EXPERIMENTS.md.

@@ -17,8 +17,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import (apply_rope, dense, lora_pair, rms_norm,
-                                 rope_freqs, weight)
+from repro.models.common import (MATMUL_PRECISION, apply_rope, dense,
+                                 lora_pair, rms_norm, rope_freqs, weight)
 
 NEG_INF = -1e30
 
@@ -91,7 +91,8 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             m, l, acc = carry
             ki, kc, vc = kin
             s = jnp.einsum("bkgqd,bkcd->bkgqc", qc, kc,
-                           preferred_element_type=jnp.float32) * scale
+                           preferred_element_type=jnp.float32,
+                           precision=MATMUL_PRECISION) * scale
             if anchor:
                 s = constrain(s, P(_BA, kh_ax, g_ax, qc_ax, None))
             qpos = q_offset + qi * q_chunk + q_pos_base       # (qc,)
@@ -108,7 +109,8 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             l_new = l * alpha + p.sum(axis=-1)
             acc_new = acc * alpha[..., None] + jnp.einsum(
                 "bkgqc,bkcd->bkgqd", p.astype(vc.dtype), vc,
-                preferred_element_type=jnp.float32)
+                preferred_element_type=jnp.float32,
+                precision=MATMUL_PRECISION)
             return (m_new, l_new, acc_new), None
 
         (m, l, acc), _ = jax.lax.scan(

@@ -19,7 +19,8 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ModelConfig, InputShape
 from repro.distributed.sharding import constrain, batch_axes
 from repro.models import layers as L
-from repro.models.common import dense, init_dense, rms_norm
+from repro.models.common import (MATMUL_PRECISION, dense, init_dense,
+                                 rms_norm)
 from repro.optim import adamw
 from repro.peft import lora as lora_mod
 
@@ -242,8 +243,8 @@ def chunked_ce(cfg, params, hidden, labels, *, chunk: int = 512):
         tot, cnt = carry
         h = jax.lax.dynamic_slice_in_dim(hidden, i * chunk, chunk, axis=1)
         y = jax.lax.dynamic_slice_in_dim(labels, i * chunk, chunk, axis=1)
-        logits = jnp.einsum("bsd,dv->bsv", h, head.astype(h.dtype)
-                            ).astype(jnp.float32)
+        logits = jnp.einsum("bsd,dv->bsv", h, head.astype(h.dtype),
+                            precision=MATMUL_PRECISION).astype(jnp.float32)
         logz = jax.nn.logsumexp(logits, axis=-1)
         gold = jnp.take_along_axis(
             logits, jnp.maximum(y, 0)[..., None], axis=-1)[..., 0]
@@ -261,8 +262,8 @@ def chunked_ce(cfg, params, hidden, labels, *, chunk: int = 512):
 def logits_last(cfg, params, hidden):
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     h = hidden[:, -1, :]
-    return jnp.einsum("bd,dv->bv", h, head.astype(h.dtype)
-                      ).astype(jnp.float32)
+    return jnp.einsum("bd,dv->bv", h, head.astype(h.dtype),
+                      precision=MATMUL_PRECISION).astype(jnp.float32)
 
 
 # ---------------------------------------------------------------------------

@@ -115,7 +115,8 @@ class Backend:
             # symmetric confusion: stay w.p. 1-f, uniform flip otherwise
             f = self.readout_flip
             conf = (1 - f) * jnp.eye(C) + f / (C - 1) * (1 - jnp.eye(C))
-            probs = probs @ conf.astype(probs.dtype)
+            probs = jnp.dot(probs, conf.astype(probs.dtype),
+                            precision=jax.lax.Precision.HIGHEST)
         return probs
 
     def sample(self, probs: jnp.ndarray, key: jax.Array) -> jnp.ndarray:

@@ -32,7 +32,8 @@ def parity_interpret(probs: jnp.ndarray, n_qubits: int,
         pop = pop + ((idx >> b) & 1)
     cls = pop % n_classes
     onehot = jax.nn.one_hot(cls, n_classes, dtype=probs.dtype)
-    return probs @ onehot
+    # full f32: the TPU's default precision would round probs to bfloat16
+    return jnp.dot(probs, onehot, precision=jax.lax.Precision.HIGHEST)
 
 
 def last_qubit_interpret(psi: jnp.ndarray, q: int) -> jnp.ndarray:

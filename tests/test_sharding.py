@@ -83,3 +83,27 @@ def test_constrain_noop_outside_mesh():
     x = jnp.ones((4, 4))
     y = shd.constrain(x, P("data", None))
     np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+def test_constrain_and_axis_size_under_mesh():
+    """Under a mesh the helpers act on it: axes it lacks are dropped,
+    its sizes are read — and errors are not swallowed any more."""
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
+    assert shd.mesh_axis_size("data") == 1 == shd.mesh_axis_size("model")
+    with jax.set_mesh(mesh):
+        assert shd.mesh_axis_size("data") == 1
+        y = jax.jit(lambda x: shd.constrain(x, P("data", "model")))(
+            jnp.ones((4, 4)))
+        np.testing.assert_array_equal(np.asarray(y), np.ones((4, 4)))
+        with pytest.raises(ValueError):
+            # a spec longer than the array's rank is a caller's bug
+            jax.jit(lambda x: shd.constrain(x, P("data", None, None)))(
+                jnp.ones((4, 4)))
+
+
+def test_bench_mesh_width_is_never_cut():
+    from benchmarks.hostdev import require_visible
+    assert require_visible(len(jax.devices()), "t") == len(jax.devices())
+    with pytest.raises(SystemExit, match="wanted"):
+        require_visible(len(jax.devices()) + 1, "t")

@@ -117,3 +117,14 @@ def test_gate_apply_controlled_identity_on_zero_control():
     g = T._mat_x(jnp.zeros((1,), jnp.float32))
     out = T.jnp_gate_apply(psi, g, jnp.int32(1), jnp.int32(0), n)
     np.testing.assert_allclose(np.asarray(out), np.asarray(psi), atol=1e-7)
+
+
+def test_statevector_gate_never_compiles_silently():
+    """The kernel has no Mosaic lowering: asking for one raises instead
+    of interpreting behind the caller's back."""
+    z = jnp.zeros((2, 4), jnp.float32)
+    g = jnp.zeros((2, 2, 2), jnp.float32)
+    i = jnp.zeros((2,), jnp.int32)
+    with pytest.raises(NotImplementedError, match="Mosaic"):
+        ops.statevector_gate(z, z, g, g, i, i, i.astype(jnp.float32),
+                             interpret=False)
