@@ -1,0 +1,6 @@
+"""LoRA-trained tokens of the window's completed stage calls over the
+time from the window's start to the end of the last call."""
+
+
+def read(ctx):
+    return sum(ctx.window.work) / ctx.window.seconds
