@@ -4,9 +4,7 @@
   PYTHONPATH=src python -m benchmarks.run regulation   # one bench
 
 Prints ``bench/name,value,derived`` CSV rows and writes JSON to
-experiments/bench/.  The roofline table is read from experiments/dryrun/
-(produce it with ``python -m repro.launch.dryrun --all``, which must run
-in its own process — it forces 512 host devices).
+experiments/bench/.
 """
 from __future__ import annotations
 
@@ -14,14 +12,13 @@ import sys
 import time
 import traceback
 
-BENCHES = ("kernels", "federated_round", "llm_round", "population",
-           "regulation", "convergence", "selection", "reg_variants",
-           "backends", "comm_cost", "llm_models", "theory", "roofline")
+BENCHES = ("federated_round", "llm_round", "population", "regulation",
+           "convergence", "selection", "reg_variants", "backends",
+           "comm_cost", "llm_models", "theory")
 
 
 def run_one(name: str) -> bool:
-    mod_name = ("benchmarks.roofline" if name == "roofline"
-                else f"benchmarks.bench_{name}")
+    mod_name = f"benchmarks.bench_{name}"
     print(f"## bench:{name}", flush=True)
     try:
         mod = __import__(mod_name, fromlist=["main"])

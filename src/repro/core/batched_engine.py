@@ -199,10 +199,8 @@ def build_local_phase(spec, backend, *, lam: float, mu: float,
         def local_phase(qX, qy, mask, teacher, theta_g, iters, ckeys,
                         deltas=None, active=None):
             x0, f = prep(qX, qy, mask, teacher, theta_g, ckeys)
-            simplex, fvals, n_evals, _ = batched_nm(f, x0, iters,
-                                                    int(max_iter),
-                                                    keyed=sampling,
-                                                    active=active)
+            simplex, fvals, n_evals, _, _ = batched_nm(
+                f, x0, iters, int(max_iter), keyed=sampling, active=active)
             x, _ = best_point(simplex, fvals)
             if active is not None:
                 # an untouched init simplex's best vertex is an offset

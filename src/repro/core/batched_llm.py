@@ -64,6 +64,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry as tel
 from repro.core import llm_client as llmc
 from repro.data.tokenizer import PAD
 from repro.distributed import sharding as shd
@@ -107,13 +108,16 @@ def _build_llm_round_fn(cfg, n_labels: int, lr: float, batch_size: int,
                  nvalid, weights, ckeys, step0):
         def body(carry, s):
             adp, opt = carry
-            keys = jax.vmap(jax.random.fold_in, in_axes=(0, None))(
-                ckeys, s)
-            idx = jax.vmap(llmc.sample_minibatch_idx,
-                           in_axes=(0, 0, None))(keys, nvalid, batch_size)
-            mb = {"tokens": jax.vmap(lambda t, i: t[i])(tokens, idx),
-                  "labels": jax.vmap(lambda t, i: t[i])(labels, idx)}
-            adp, opt, metrics = vstep(base, adp, opt, mb)
+            with jax.named_scope(tel.LLM_SAMPLE):
+                keys = jax.vmap(jax.random.fold_in, in_axes=(0, None))(
+                    ckeys, s)
+                idx = jax.vmap(llmc.sample_minibatch_idx,
+                               in_axes=(0, 0, None))(keys, nvalid,
+                                                     batch_size)
+                mb = {"tokens": jax.vmap(lambda t, i: t[i])(tokens, idx),
+                      "labels": jax.vmap(lambda t, i: t[i])(labels, idx)}
+            with jax.named_scope(tel.LLM_STEP):
+                adp, opt, metrics = vstep(base, adp, opt, mb)
             return (adp, opt), metrics["loss"]
 
         # step0 is the GLOBAL step offset (traced — a refresh does not
@@ -123,10 +127,12 @@ def _build_llm_round_fn(cfg, n_labels: int, lr: float, batch_size: int,
             body, (adapters, opt_state), step0 + jnp.arange(steps))
         # Alg. 1 line 8 on device: FedAvg teacher (the one cross-client
         # reduction of this program) + distillation blend
-        a_g = lora_mod.weighted_average_stacked(adapters, weights)
-        adapters = lora_mod.blend_adapters(adapters, a_g, rho)
-        losses, f1s, teacher = veval(base, adapters, tokens, labels,
-                                     rowmask)
+        with jax.named_scope(tel.LLM_FEDAVG):
+            a_g = lora_mod.weighted_average_stacked(adapters, weights)
+            adapters = lora_mod.blend_adapters(adapters, a_g, rho)
+        with jax.named_scope(tel.LLM_EVAL):
+            losses, f1s, teacher = veval(base, adapters, tokens, labels,
+                                         rowmask)
         return adapters, opt_state, a_g, losses, f1s, teacher, tlosses[-1]
 
     return round_fn
@@ -217,9 +223,15 @@ class BatchedLLMEngine:
         self._c_pad = c_pad
         self._steps = int(steps)
         self._n_steps = 0             # global step counter (key contract)
+        self._calls = 0               # run() calls: the host step number
         self._round = get_llm_round_fn(cfg, n_labels=n_labels, lr=lr,
                                        batch_size=batch_size, steps=steps,
                                        rho=rho)
+
+    def _args(self) -> tuple:
+        return (self._base, self.adapters, self.opt_state, self._tokens,
+                self._labels, self._rowmask, self._nvalid, self._weights,
+                self._ckeys, jnp.int32(self._n_steps))
 
     def run(self) -> LLMRoundResult:
         """Fine-tune all clients, distill toward the FedAvg teacher, and
@@ -227,18 +239,27 @@ class BatchedLLMEngine:
         adapter/optimizer state and advances the global step counter, so
         a later refresh continues from both (draws resume at step
         ``_n_steps``, matching the sequential wrapper's counter)."""
-        (self.adapters, self.opt_state, self.a_g, losses, f1s, teacher,
-         tlast) = self._round(self._base, self.adapters, self.opt_state,
-                              self._tokens, self._labels, self._rowmask,
-                              self._nvalid, self._weights, self._ckeys,
-                              jnp.int32(self._n_steps))
-        self._n_steps += self._steps
-        C = self._n_clients
-        return LLMRoundResult(
-            losses=np.asarray(losses, np.float64)[:C],
-            f1=np.asarray(f1s, np.float64)[:C],
-            teacher=np.asarray(teacher, np.float32)[:C],
-            final_train_loss=np.asarray(tlast, np.float64)[:C])
+        self._calls += 1
+        with jax.profiler.StepTraceAnnotation(tel.LLM_STAGE,
+                                              step_num=self._calls):
+            with jax.profiler.TraceAnnotation(tel.LLM_STAGE_DISPATCH):
+                (self.adapters, self.opt_state, self.a_g, losses, f1s,
+                 teacher, tlast) = self._round(*self._args())
+            self._n_steps += self._steps
+            C = self._n_clients
+            with jax.profiler.TraceAnnotation(
+                    tel.LLM_STAGE_FETCH,
+                    bytes=tel.nbytes((losses, f1s, teacher, tlast))):
+                return LLMRoundResult(
+                    losses=np.asarray(losses, np.float64)[:C],
+                    f1=np.asarray(f1s, np.float64)[:C],
+                    teacher=np.asarray(teacher, np.float32)[:C],
+                    final_train_loss=np.asarray(tlast, np.float64)[:C])
+
+    def compiled_text(self) -> str:
+        """The optimized HLO text of the program ``run()`` executes next
+        (lowered on its own arguments; the compilation cache serves it)."""
+        return self._round.lower(*self._args()).compile().as_text()
 
     def teacher_probs_list(self, task, teacher: np.ndarray) -> List:
         """Slice the padded (C, Nmax, n_labels) teacher stack back into

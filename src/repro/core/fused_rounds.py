@@ -84,10 +84,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry as tel
 from repro.core import regulation as regulation_mod
 from repro.core.batched_engine import build_local_phase
 from repro.core.termination import TerminationCriterion
 from repro.distributed import sharding as shd
+from repro.optim.batched_nm import lockstep_iters
 from repro.optim.batched_spsa import make_deltas
 from repro.quantum import backends as backend_mod
 from repro.quantum import qnn, tape as tape_mod
@@ -246,139 +248,155 @@ def _build_fused_program(spec, backend, *, lam, mu, use_llm, optimizer,
              prev_loss, small, done) = carry
             run = ~done
 
-            # -- cohort ---------------------------------------------------
-            if subsample:
-                ck = backend_mod.eval_key(base_key, t,
-                                          backend_mod.POP_CLIENT,
-                                          backend_mod.POP_SLOT_COHORT)
-                cohort = jnp.sort(jax.random.choice(
-                    ck, c_pop, (c_width,), replace=False)).astype(jnp.int32)
-                real = jnp.ones((c_width,), bool)
-            else:
-                cohort = jnp.arange(c_pad, dtype=jnp.int32)
-                real = is_real_pad
-            if dropout > 0.0:
-                u = jax.vmap(lambda cid: jax.random.uniform(
-                    backend_mod.eval_key(base_key, t, cid,
-                                         backend_mod.DROPOUT_EVAL_SLOT)))(
-                    cohort)
-                dropped = (u < dropout) & real
-            else:
-                dropped = jnp.zeros((c_width,), bool)
-            eligible = real & ~dropped
+            # -- cohort, and the gather of its rows -----------------------
+            with jax.named_scope(tel.QFL_GATHER):
+                if subsample:
+                    ck = backend_mod.eval_key(base_key, t,
+                                              backend_mod.POP_CLIENT,
+                                              backend_mod.POP_SLOT_COHORT)
+                    cohort = jnp.sort(jax.random.choice(
+                        ck, c_pop, (c_width,),
+                        replace=False)).astype(jnp.int32)
+                    real = jnp.ones((c_width,), bool)
+                else:
+                    cohort = jnp.arange(c_pad, dtype=jnp.int32)
+                    real = is_real_pad
+                if dropout > 0.0:
+                    u = jax.vmap(lambda cid: jax.random.uniform(
+                        backend_mod.eval_key(
+                            base_key, t, cid,
+                            backend_mod.DROPOUT_EVAL_SLOT)))(cohort)
+                    dropped = (u < dropout) & real
+                else:
+                    dropped = jnp.zeros((c_width,), bool)
+                eligible = real & ~dropped
 
-            # -- gather the cohort's rows --------------------------------
-            if subsample:
-                def g(a):
-                    return shd.constrain_client_axis(
-                        jnp.take(a, cohort, axis=0), mesh)
-                gqX, gqy, gmask, gteacher = g(qX), g(qy), g(mask), g(teacher)
-                gdeltas, gweights = g(deltas), g(weights)
-                gevaltime, gllm = g(evaltime), g(llm)
-                gbud0, glast = g(budgets), g(last_losses)
-            else:
-                gqX, gqy, gmask, gteacher = qX, qy, mask, teacher
-                gdeltas, gweights, gevaltime, gllm = (deltas, weights,
-                                                      evaltime, llm)
-                gbud0, glast = budgets, last_losses
+                if subsample:
+                    def g(a):
+                        return shd.constrain_client_axis(
+                            jnp.take(a, cohort, axis=0), mesh)
+                    gqX, gqy, gmask = g(qX), g(qy), g(mask)
+                    gteacher = g(teacher)
+                    gdeltas, gweights = g(deltas), g(weights)
+                    gevaltime, gllm = g(evaltime), g(llm)
+                    gbud0, glast = g(budgets), g(last_losses)
+                else:
+                    gqX, gqy, gmask, gteacher = qX, qy, mask, teacher
+                    gdeltas, gweights, gevaltime, gllm = (deltas, weights,
+                                                          evaltime, llm)
+                    gbud0, glast = budgets, last_losses
 
             # -- regulation (Alg. 1 lines 11-17; after round 1 only) ------
-            if use_llm:
-                boosted = regulate_batched(gbud0, glast, gllm,
-                                           variant=regulation,
-                                           cap=maxiter_cap)
-                gbud = jnp.where((t > 1) & eligible, boosted, gbud0)
-                gratios = jnp.where(
-                    (t > 1) & jnp.isfinite(glast) & (gllm > 0.0),
-                    glast / gllm, jnp.float32(1.0))
-            else:
-                gbud = gbud0
-                gratios = jnp.ones((c_width,), jnp.float32)
+            with jax.named_scope(tel.QFL_REGULATE):
+                if use_llm:
+                    boosted = regulate_batched(gbud0, glast, gllm,
+                                               variant=regulation,
+                                               cap=maxiter_cap)
+                    gbud = jnp.where((t > 1) & eligible, boosted, gbud0)
+                    gratios = jnp.where(
+                        (t > 1) & jnp.isfinite(glast) & (gllm > 0.0),
+                        glast / gllm, jnp.float32(1.0))
+                else:
+                    gbud = gbud0
+                    gratios = jnp.ones((c_width,), jnp.float32)
 
             # -- local phase: the engine's traceable body -----------------
-            rk = jax.random.fold_in(base_key, t)
-            ckeys = jax.vmap(jax.random.fold_in, in_axes=(None, 0))(rk,
-                                                                    cohort)
-            th, n_evals = local_phase(gqX, gqy, gmask, gteacher, theta_g,
-                                      gbud, ckeys, deltas=gdeltas,
-                                      active=eligible)
+            with jax.named_scope(tel.QFL_LOCAL):
+                rk = jax.random.fold_in(base_key, t)
+                ckeys = jax.vmap(jax.random.fold_in, in_axes=(None, 0))(
+                    rk, cohort)
+                th, n_evals = local_phase(gqX, gqy, gmask, gteacher,
+                                          theta_g, gbud, ckeys,
+                                          deltas=gdeltas, active=eligible)
+                # the lockstep loop's trip count, by the rule its bound
+                # follows (optim/batched_nm.lockstep_iters)
+                nm_iters = (lockstep_iters(gbud, max_iter, eligible)
+                            if optimizer == "nelder-mead" else jnp.int32(0))
 
             # -- report F_i from the carry (no host loop) -----------------
-            glosses = jax.vmap(report_one)(th, gqX, gqy, gmask, ckeys)
-            glosses = jnp.where(eligible, glosses, jnp.nan)
+            with jax.named_scope(tel.QFL_REPORT):
+                glosses = jax.vmap(report_one)(th, gqX, gqy, gmask, ckeys)
+                glosses = jnp.where(eligible, glosses, jnp.nan)
 
-            s_pre = server_nll(theta_g, val_qX, val_qy, t,
-                               backend_mod.SERVER_SLOT_LOSS_PRE)
+            with jax.named_scope(tel.QFL_SERVER):   # before FedAvg
+                s_pre = server_nll(theta_g, val_qX, val_qy, t,
+                                   backend_mod.SERVER_SLOT_LOSS_PRE)
 
             # -- alignment selection (Sec. III-B) -------------------------
-            if select_on:
-                d = jnp.abs(glosses - s_pre)
-                d = jnp.where(jnp.isfinite(d) & eligible, d, jnp.inf)
-                if k_static is not None:
-                    k = k_static
+            with jax.named_scope(tel.QFL_SELECT):
+                if select_on:
+                    d = jnp.abs(glosses - s_pre)
+                    d = jnp.where(jnp.isfinite(d) & eligible, d, jnp.inf)
+                    if k_static is not None:
+                        k = k_static
+                    else:
+                        n_el = jnp.sum(eligible).astype(jnp.float32)
+                        k = jnp.maximum(1, jnp.round(
+                            select_frac * n_el)).astype(jnp.int32)
+                    sel = select_topk_mask(d, k) & eligible
                 else:
-                    n_el = jnp.sum(eligible).astype(jnp.float32)
-                    k = jnp.maximum(
-                        1, jnp.round(select_frac * n_el)).astype(jnp.int32)
-                sel = select_topk_mask(d, k) & eligible
-            else:
-                sel = eligible
+                    sel = eligible
 
             # -- FedAvg (Eq. 3) over the selected set ---------------------
-            w = jnp.where(sel, gweights, 0.0)
-            wsum = jnp.sum(w)
-            theta_new = jnp.sum(
-                (w / jnp.maximum(wsum, 1e-30))[:, None] * th, axis=0)
-            theta_new = jnp.where(wsum > 0, theta_new, theta_g)
-            theta_g = jnp.where(run, theta_new, theta_g)
+            with jax.named_scope(tel.QFL_FEDAVG):
+                w = jnp.where(sel, gweights, 0.0)
+                wsum = jnp.sum(w)
+                theta_new = jnp.sum(
+                    (w / jnp.maximum(wsum, 1e-30))[:, None] * th, axis=0)
+                theta_new = jnp.where(wsum > 0, theta_new, theta_g)
+                theta_g = jnp.where(run, theta_new, theta_g)
 
-            s_post = server_nll(theta_g, val_qX, val_qy, t,
-                                backend_mod.SERVER_SLOT_LOSS_POST)
-            v_acc = server_acc(theta_g, val_qX, val_qy, t,
-                               backend_mod.SERVER_SLOT_VAL_ACC)
-            t_acc = server_acc(theta_g, test_qX, test_qy, t,
-                               backend_mod.SERVER_SLOT_TEST_ACC)
+            with jax.named_scope(tel.QFL_SERVER):   # after FedAvg
+                s_post = server_nll(theta_g, val_qX, val_qy, t,
+                                    backend_mod.SERVER_SLOT_LOSS_POST)
+                v_acc = server_acc(theta_g, val_qX, val_qy, t,
+                                   backend_mod.SERVER_SLOT_VAL_ACC)
+                t_acc = server_acc(theta_g, test_qX, test_qy, t,
+                                   backend_mod.SERVER_SLOT_TEST_ACC)
 
             # -- termination ---------------------------------------------
-            stop, small_new = termination_step(
-                prev_loss, small, s_post, t, epsilon=epsilon,
-                t_max=n_rounds, patience=patience)
-            prev_loss = jnp.where(run, s_post, prev_loss)
-            small = jnp.where(run, small_new, small)
-            if early_stop:
-                done_next = done | (run & stop)
-            else:
-                done_next = done
+            with jax.named_scope(tel.QFL_TERMINATE):
+                stop, small_new = termination_step(
+                    prev_loss, small, s_post, t, epsilon=epsilon,
+                    t_max=n_rounds, patience=patience)
+                prev_loss = jnp.where(run, s_post, prev_loss)
+                small = jnp.where(run, small_new, small)
+                if early_stop:
+                    done_next = done | (run & stop)
+                else:
+                    done_next = done
 
             # -- scatter cohort state back to the population carries ------
-            upd = run & eligible
-            evals_add = jnp.where(upd, n_evals, 0)
-            if subsample:
-                budgets = budgets.at[cohort].set(
-                    jnp.where(upd, gbud, gbud0))
-                last_losses = last_losses.at[cohort].set(
-                    jnp.where(upd, glosses, glast))
-                cum_evals = cum_evals.at[cohort].add(evals_add)
-            else:
-                budgets = jnp.where(upd, gbud, budgets)
-                last_losses = jnp.where(upd, glosses, last_losses)
-                cum_evals = cum_evals + evals_add
-            if mesh is not None:
-                # full participation: the carries ARE the sharded client
-                # stacks.  Population mode: carries stay replicated (the
-                # scatter of sharded cohort values must not let GSPMD
-                # drift the carry sharding between scan iterations).
-                pin = (shd.constrain_replicated if subsample
-                       else shd.constrain_client_axis)
-                budgets = pin(budgets, mesh)
-                last_losses = pin(last_losses, mesh)
-                cum_evals = pin(cum_evals, mesh)
+            with jax.named_scope(tel.QFL_SCATTER):
+                upd = run & eligible
+                evals_add = jnp.where(upd, n_evals, 0)
+                if subsample:
+                    budgets = budgets.at[cohort].set(
+                        jnp.where(upd, gbud, gbud0))
+                    last_losses = last_losses.at[cohort].set(
+                        jnp.where(upd, glosses, glast))
+                    cum_evals = cum_evals.at[cohort].add(evals_add)
+                else:
+                    budgets = jnp.where(upd, gbud, budgets)
+                    last_losses = jnp.where(upd, glosses, last_losses)
+                    cum_evals = cum_evals + evals_add
+                if mesh is not None:
+                    # full participation: the carries ARE the sharded
+                    # client stacks.  Population mode: carries stay
+                    # replicated (the scatter of sharded cohort values must
+                    # not let GSPMD drift the carry sharding between scan
+                    # iterations).
+                    pin = (shd.constrain_replicated if subsample
+                           else shd.constrain_client_axis)
+                    budgets = pin(budgets, mesh)
+                    last_losses = pin(last_losses, mesh)
+                    cum_evals = pin(cum_evals, mesh)
 
-            comm = jnp.max(jnp.where(
-                eligible,
-                gevaltime * (n_evals - init_evals).astype(jnp.float32),
-                0.0))
-            comm = jnp.where(run, comm, 0.0)
+                comm = jnp.max(jnp.where(
+                    eligible,
+                    gevaltime * (n_evals - init_evals).astype(jnp.float32),
+                    0.0))
+                comm = jnp.where(run, comm, 0.0)
 
             ys = dict(active=run, stop=run & stop, cohort=cohort,
                       dropped=dropped, selected=sel, losses=glosses,
@@ -386,7 +404,7 @@ def _build_fused_program(spec, backend, *, lam, mu, use_llm, optimizer,
                       budgets=budgets, cum_evals=cum_evals,
                       server_loss_pre=s_pre, server_loss=s_post,
                       val_acc=v_acc, test_acc=t_acc, comm_time_s=comm,
-                      theta=theta_g)
+                      theta=theta_g, nm_iters=nm_iters)
             carry = (theta_g, budgets, last_losses, cum_evals,
                      prev_loss, small, done_next)
             return carry, ys
@@ -455,6 +473,8 @@ class FusedRunOutput:
     test_acc: np.ndarray          # (R,)
     comm_time_s: np.ndarray       # (R,)
     theta: np.ndarray             # (R, P) θ_g after each round
+    nm_iters: np.ndarray          # (R,)  Nelder–Mead iterations the
+    #                               round's lockstep loop ran (0: SPSA)
     theta_g: np.ndarray           # (P,)  final global parameters
     budgets_final: np.ndarray     # (c_pad,)
     last_losses_final: np.ndarray  # (c_pad,)
@@ -606,6 +626,7 @@ class FusedRoundDriver:
                                          **self._cfg)
         self._fwd = None          # host-reference lazies
         self._local_jit = None
+        self._calls = 0           # run() calls: the host step number
 
     # -- fused path ---------------------------------------------------------
     def program_args(self, theta_g) -> tuple:
@@ -621,9 +642,27 @@ class FusedRoundDriver:
     def run(self, theta_g) -> FusedRunOutput:
         """All R rounds as one program execution; one device→host
         transfer for the whole run's outputs."""
-        out = self.program(*self.program_args(theta_g))
-        host = jax.device_get(out)
-        return FusedRunOutput(**{k: np.asarray(v) for k, v in host.items()})
+        self._calls += 1
+        with jax.profiler.StepTraceAnnotation(tel.QFL_ROUNDS,
+                                              step_num=self._calls):
+            with jax.profiler.TraceAnnotation(tel.QFL_ROUNDS_ARGS):
+                args = self.program_args(theta_g)
+            with jax.profiler.TraceAnnotation(tel.QFL_ROUNDS_DISPATCH):
+                out = self.program(*args)
+            with jax.profiler.TraceAnnotation(tel.QFL_ROUNDS_FETCH,
+                                              bytes=tel.nbytes(out)):
+                host = jax.device_get(out)
+            with jax.profiler.TraceAnnotation(tel.QFL_ROUNDS_UNPACK):
+                return FusedRunOutput(**{k: np.asarray(v)
+                                         for k, v in host.items()})
+
+    def compiled_text(self, theta_g=None) -> str:
+        """The optimized HLO text of the fused program for a run from
+        ``theta_g`` (zeros by default; the compilation cache serves it)."""
+        if theta_g is None:
+            theta_g = np.zeros(self.spec.n_params, np.float32)
+        return self.program.lower(
+            *self.program_args(theta_g)).compile().as_text()
 
     # -- host-reference path (the per-round loop baseline / oracle) ---------
     def _host_round_pieces(self):
@@ -689,7 +728,8 @@ class FusedRoundDriver:
             cum_evals=np.zeros((R, c_pad), np.int32),
             server_loss_pre=znan(R), server_loss=znan(R), val_acc=znan(R),
             test_acc=znan(R), comm_time_s=np.zeros(R, np.float32),
-            theta=np.zeros((R, theta.size), np.float64))
+            theta=np.zeros((R, theta.size), np.float64),
+            nm_iters=np.zeros(R, np.int32))
 
         def nll_host(th, X, y, t, client, slot):
             probs = fwd(jnp.asarray(th, jnp.float32), jnp.asarray(X))
@@ -822,6 +862,9 @@ class FusedRoundDriver:
             out["test_acc"][r] = t_acc
             out["comm_time_s"][r] = comm
             out["theta"][r] = theta
+            if self.optimizer == "nelder-mead":
+                out["nm_iters"][r] = min(
+                    int(np.max(np.where(eligible, gbud, 0))), self.max_iter)
 
             if term.update(s_post, t):
                 out["stop"][r] = True

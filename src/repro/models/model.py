@@ -23,6 +23,7 @@ from repro.models.common import (MATMUL_PRECISION, dense, init_dense,
                                  rms_norm)
 from repro.optim import adamw
 from repro.peft import lora as lora_mod
+from repro.telemetry import MODEL_HEAD
 
 
 # ---------------------------------------------------------------------------
@@ -233,6 +234,11 @@ def _none_like(groups):
 def chunked_ce(cfg, params, hidden, labels, *, chunk: int = 512):
     """Scan over sequence chunks so (B, chunk, V) logits are the only live
     vocab-sized tensor.  labels < 0 are masked."""
+    with jax.named_scope(MODEL_HEAD):
+        return _chunked_ce(cfg, params, hidden, labels, chunk)
+
+
+def _chunked_ce(cfg, params, hidden, labels, chunk):
     B, S, d = hidden.shape
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     chunk = min(chunk, S)
@@ -260,10 +266,11 @@ def chunked_ce(cfg, params, hidden, labels, *, chunk: int = 512):
 
 
 def logits_last(cfg, params, hidden):
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    h = hidden[:, -1, :]
-    return jnp.einsum("bd,dv->bv", h, head.astype(h.dtype),
-                      precision=MATMUL_PRECISION).astype(jnp.float32)
+    with jax.named_scope(MODEL_HEAD):
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        h = hidden[:, -1, :]
+        return jnp.einsum("bd,dv->bv", h, head.astype(h.dtype),
+                          precision=MATMUL_PRECISION).astype(jnp.float32)
 
 
 # ---------------------------------------------------------------------------

@@ -6,6 +6,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.telemetry import LLM_ADAMW
+
 
 class AdamWState(NamedTuple):
     step: jnp.ndarray
@@ -21,6 +23,12 @@ def init(params) -> AdamWState:
 
 def update(grads, state: AdamWState, params, *, lr=1e-4, b1=0.9, b2=0.999,
            eps=1e-8, weight_decay=0.0):
+    with jax.named_scope(LLM_ADAMW):
+        return _update(grads, state, params, lr=lr, b1=b1, b2=b2, eps=eps,
+                       weight_decay=weight_decay)
+
+
+def _update(grads, state, params, *, lr, b1, b2, eps, weight_decay):
     step = state.step + 1
     t = step.astype(jnp.float32)
     c1 = 1.0 - b1 ** t

@@ -56,6 +56,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.telemetry import NM_INIT, NM_ITERATE
+
 # branch codes, aligned with gradfree.nm_run(trace=...)
 BRANCH_EXPAND_XE = 0      # fr < f_best, fe < fr  → worst ← xe   (2 evals)
 BRANCH_EXPAND_XR = 1      # fr < f_best, fe ≥ fr  → worst ← xr   (2 evals)
@@ -96,9 +98,11 @@ def batched_nm(f: Callable, x0: jnp.ndarray, iters: jnp.ndarray,
                the all-active behavior.
 
     Returns ``(simplex (C, n+1, P), fvals (C, n+1), n_evals (C,),
-    branches (C, max_iter) int32)``.  ``n_evals`` counts what the
-    sequential path spends: ``n+1`` init plus the taken branch's evals per
-    iteration.  The best point is ``simplex[c, argmin(fvals[c])]``.
+    branches (C, max_iter) int32, n_steps () int32)``.  ``n_evals``
+    counts what the sequential path spends: ``n+1`` init plus the taken
+    branch's evals per iteration.  ``n_steps`` is the loop's trip count,
+    the iterations every client ran in lockstep (``lockstep_iters``).
+    The best point is ``simplex[c, argmin(fvals[c])]``.
     """
     x0 = jnp.asarray(x0, jnp.float32)
     iters = jnp.asarray(iters, jnp.int32)
@@ -114,11 +118,12 @@ def batched_nm(f: Callable, x0: jnp.ndarray, iters: jnp.ndarray,
         fstack = lambda cand, slots: jax.vmap(
             lambda xs: f(xs), in_axes=1, out_axes=1)(cand)
 
-    simplex0 = init_simplexes(x0, step=step)
-    fvals0 = fstack(simplex0, jnp.arange(n + 1))             # (C, n+1)
-    evals0 = jnp.full((C,), n + 1, jnp.int32)
-    if active is not None:
-        evals0 = jnp.where(active, evals0, 0)
+    with jax.named_scope(NM_INIT):
+        simplex0 = init_simplexes(x0, step=step)
+        fvals0 = fstack(simplex0, jnp.arange(n + 1))         # (C, n+1)
+        evals0 = jnp.full((C,), n + 1, jnp.int32)
+        if active is not None:
+            evals0 = jnp.where(active, evals0, 0)
     branches0 = jnp.full((C, int(max_iter)), BRANCH_INACTIVE, jnp.int32)
 
     def body(i, carry):
@@ -176,10 +181,22 @@ def batched_nm(f: Callable, x0: jnp.ndarray, iters: jnp.ndarray,
             (0, i))
         return simplex, fvals, evals, branches
 
-    n_steps = jnp.minimum(jnp.max(iters), max_iter)
-    out = jax.lax.fori_loop(0, n_steps, body,
-                            (simplex0, fvals0, evals0, branches0))
-    return out
+    with jax.named_scope(NM_ITERATE):
+        n_steps = lockstep_iters(iters, max_iter)
+        out = jax.lax.fori_loop(0, n_steps, body,
+                                (simplex0, fvals0, evals0, branches0))
+    return (*out, n_steps)
+
+
+def lockstep_iters(iters: jnp.ndarray, max_iter: int,
+                   active: jnp.ndarray = None) -> jnp.ndarray:
+    """The trip count of ``batched_nm``'s loop: the largest budget of an
+    active client, capped at ``max_iter``.  Every client runs (and pays
+    the ``n+3`` candidates of) each of these iterations."""
+    iters = jnp.asarray(iters, jnp.int32)
+    if active is not None:
+        iters = jnp.where(jnp.asarray(active, bool), iters, 0)
+    return jnp.minimum(jnp.max(iters), max_iter)
 
 
 def best_point(simplex: jnp.ndarray, fvals: jnp.ndarray

@@ -41,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.quantum import statevector as sv
+from repro.telemetry import TAPE_REPLAY
 
 GATE_H, GATE_P, GATE_RY, GATE_RZ, GATE_X = 0, 1, 2, 3, 4
 
@@ -316,15 +317,16 @@ def tape_probs(cq: CompiledQNN, theta: jnp.ndarray, X: jnp.ndarray, *,
                gate_apply: Optional[Callable] = None) -> jnp.ndarray:
     """Class probabilities (B, n_classes), matching ``qnn._forward_one``."""
     from repro.quantum import qnn
-    angles = tape_angles(cq.tape, X, theta)
-    psi = run_tape(cq.tape, angles, gate_apply=gate_apply)
-    probs = jnp.abs(psi) ** 2                            # (B, 2**n)
-    if cq.kind == "qcnn" and cq.n_classes == 2:
-        B = probs.shape[0]
-        q = cq.readout
-        grouped = probs.reshape(B, 1 << q, 2, -1)
-        return grouped.sum(axis=(1, 3))
-    return qnn.parity_interpret(probs, cq.n_qubits, cq.n_classes)
+    with jax.named_scope(TAPE_REPLAY):
+        angles = tape_angles(cq.tape, X, theta)
+        psi = run_tape(cq.tape, angles, gate_apply=gate_apply)
+        probs = jnp.abs(psi) ** 2                        # (B, 2**n)
+        if cq.kind == "qcnn" and cq.n_classes == 2:
+            B = probs.shape[0]
+            q = cq.readout
+            grouped = probs.reshape(B, 1 << q, 2, -1)
+            return grouped.sum(axis=(1, 3))
+        return qnn.parity_interpret(probs, cq.n_qubits, cq.n_classes)
 
 
 def make_tape_forward(spec, *, gate_apply: Optional[Callable] = None

@@ -31,7 +31,7 @@ def test_batched_nm_matches_sequential_per_client():
     centers = [np.linspace(-1, 1, dim) * (c + 1) for c in range(3)]
     x0 = np.full((3, dim), 0.5, np.float32)
 
-    simplex, fvals, n_evals, branches = batched_nm(
+    simplex, fvals, n_evals, branches, _ = batched_nm(
         _quad_batch(centers), x0, iters, 12)
     xb, fb = best_point(simplex, fvals)
 
@@ -61,7 +61,7 @@ def test_batched_nm_exercises_all_branches():
                     + 100.0 * (xs[:, 1] - xs[:, 0] ** 2) ** 2)
     x0 = np.array([[-1.2, 1.0]], np.float32)
     m = 60
-    _, _, n_evals, branches = batched_nm(f, x0, np.array([m]), m)
+    _, _, n_evals, branches, _ = batched_nm(f, x0, np.array([m]), m)
 
     trace = []
     st = gradfree.nm_init(rosen_h, x0[0])
@@ -79,7 +79,7 @@ def test_batched_nm_eval_accounting_per_branch():
     centers = [np.ones(dim) * 2.0]
     x0 = np.zeros((1, dim), np.float32)
     m = 15
-    _, _, n_evals, branches = batched_nm(_quad_batch(centers), x0,
+    _, _, n_evals, branches, _ = batched_nm(_quad_batch(centers), x0,
                                          np.array([m]), m)
     cost = {BRANCH_EXPAND_XE: 2, BRANCH_EXPAND_XR: 2, BRANCH_REFLECT: 1,
             BRANCH_CONTRACT: 2, BRANCH_SHRINK: 2 + dim}
@@ -91,7 +91,7 @@ def test_batched_nm_converges_quadratic():
     # mirrors test_gradfree.test_nm_converges_quadratic (dim 4, 150 iters)
     centers = [np.ones(4)]
     x0 = np.zeros((1, 4), np.float32)
-    simplex, fvals, _, _ = batched_nm(_quad_batch(centers), x0,
+    simplex, fvals, _, _, _ = batched_nm(_quad_batch(centers), x0,
                                       np.array([150]), 150)
     _, fb = best_point(simplex, fvals)
     assert float(fb[0]) < 1e-6
@@ -103,7 +103,7 @@ def test_batched_nm_budget_masks_are_prefixes():
     dim = 4
     centers = [np.linspace(0.5, 2.0, dim)] * 2
     x0 = np.full((2, dim), 0.25, np.float32)
-    _, _, _, branches = batched_nm(_quad_batch(centers), x0,
+    _, _, _, branches, _ = batched_nm(_quad_batch(centers), x0,
                                    np.array([4, 10]), 10)
     short = [int(b) for b in branches[0] if b != BRANCH_INACTIVE]
     long = [int(b) for b in branches[1]]

@@ -10,6 +10,9 @@ chip's HBM.  These tests compile, without running:
     README quickstart task, within one chip's HBM;
   - the fused round program at 4 qubits with 5 clients.
 
+The two programs' compiled texts also carry the program's layer scopes
+(``repro.telemetry``), as the chip's compiler leaves them.
+
 The topology is described inside a module fixture, never while a module
 is imported: one process at a time may load the TPU library, and under
 pytest-xdist every worker imports every test file.
@@ -22,6 +25,8 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from hlo_scopes import scopes, unscoped_share
+from repro import telemetry as tel
 from repro.core import llm_client as llmc
 from repro.core.batched_llm import get_llm_round_fn
 from repro.core.fused_rounds import FusedRoundDriver
@@ -118,11 +123,15 @@ def test_llm_stage_fits_one_chip(one_chip, task):
             _sds((C, n, L), jnp.int32), _sds((C, n), jnp.float32),
             _sds((C,), jnp.int32), _sds((C,), jnp.float32), ckeys,
             _sds((), jnp.int32))
-    mem = fn.lower(*_on(one_chip, args)).compile().memory_analysis()
+    compiled = fn.lower(*_on(one_chip, args)).compile()
+    mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert mem.argument_size_in_bytes > 3.5e9     # the 1B f32 base is in
     assert used < HBM_BYTES, (mem.argument_size_in_bytes,
                               mem.temp_size_in_bytes)
+    text = compiled.as_text()
+    assert set(tel.LLM_SCOPES) <= scopes(text)
+    assert unscoped_share(text) < 0.1
 
 
 def test_fused_rounds_compile(one_chip, task):
@@ -138,3 +147,8 @@ def test_fused_rounds_compile(one_chip, task):
     args = driver.program_args(np.zeros(spec.n_params))
     compiled = driver.program.lower(*_on(one_chip, args)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < HBM_BYTES
+    # every client selected: no selection, no cohort to gather
+    text = compiled.as_text()
+    assert set(tel.ROUND_SCOPES) - {tel.QFL_SELECT, tel.QFL_GATHER} \
+        <= scopes(text)
+    assert unscoped_share(text) < 0.1
