@@ -7,7 +7,8 @@ clients, every regulated SPSA iteration, the distillation objective — as
 a single compiled device program built from:
 
   - the circuit tape compiler (``repro.quantum.tape``): the client QNN as
-    a ``lax.scan`` over fused batched gate kernels on flat statevectors,
+    a tape replayed gate by gate as straight-line code on batched
+    statevectors,
   - a device-resident masked optimizer — batched SPSA
     (``repro.optim.batched_spsa``) or batched Nelder–Mead
     (``repro.optim.batched_nm``, the paper's default method run natively:

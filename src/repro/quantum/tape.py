@@ -7,9 +7,11 @@ same circuits are compiled **once** into a flat tape of
 
   (gate_id, target, control, angle-source)
 
-rows and replayed with ``lax.scan`` over a single batched gate kernel that
-operates on ``(B, 2**n)`` flattened statevectors.  Every gate the paper's
-three circuits need reduces to an (optionally controlled) 2×2 unitary:
+rows and replayed by ``run_tape``, unrolled at trace time: the gate
+kinds, targets and controls are Python ints, so each replay is one
+straight line of gates (no loop, no branch, no gather) and only the
+angles are data.  Every gate the paper's three circuits need reduces to
+an (optionally controlled) 2×2 unitary:
 
   H, P(θ), RY(θ), RZ(θ), and CX = controlled-X.
 
@@ -25,10 +27,16 @@ Angle sources cover the three ways an angle is produced:
 Qubit convention matches ``statevector.py``: qubit 0 is the leftmost
 tensor axis, i.e. bit ``n-1-q`` of the flat big-endian index.
 
-The batched gate apply has three interchangeable implementations:
-the fused jnp path below (default), the ``kernels/statevector_gates.py``
-Pallas kernel (``gate_apply=tape.pallas_gate_apply``), and the
-``kernels/ref.py`` oracle — all contracted equal by ``tests/test_tape.py``.
+A gate acts on the ``(B, 2, …, 2)`` view of the ``(B, 2**n)`` batch of
+statevectors: static slices take the two halves of the target's axis
+(of the control-1 half of the control's axis, for a controlled gate),
+the 2×2 matrix mixes them in complex64, and the halves are joined
+again; CX exchanges them.  The gate apply has three interchangeable
+implementations: the jnp path below (default), the
+``kernels/statevector_gates.py`` Pallas kernel
+(``gate_apply=tape.pallas_gate_apply``, which gathers by index pairs and
+runs interpreted), and the ``kernels/ref.py`` oracle — all contracted
+equal by ``tests/test_tape.py``.
 """
 from __future__ import annotations
 
@@ -211,57 +219,69 @@ def compile_qnn(spec) -> CompiledQNN:
 # ---------------------------------------------------------------------------
 # execution
 # ---------------------------------------------------------------------------
+def _take_cols(a: jnp.ndarray, idx: np.ndarray) -> jnp.ndarray:
+    """``a[:, idx]`` for a static index array, as static slices joined
+    into one table: no gather."""
+    cols = [a[:, k:k + 1] for k in range(a.shape[1])]
+    return jnp.concatenate([cols[k] for k in idx.tolist()], axis=1)
+
+
 def tape_angles(tape: GateTape, X: jnp.ndarray,
                 theta: jnp.ndarray) -> jnp.ndarray:
     """Resolve per-gate angles for a batch of examples → (B, G) float32."""
-    xi = X[:, tape.xi]                                   # (B, G)
-    xj = X[:, tape.xj]
+    xi = _take_cols(X, tape.xi)                          # (B, G)
+    xj = _take_cols(X, tape.xj)
     xterm = jnp.where(
         tape.xmode == XMODE_LINEAR, 2.0 * xi,
         jnp.where(tape.xmode == XMODE_ZZ,
                   2.0 * (jnp.pi - xi) * (jnp.pi - xj), 0.0))
     theta_pad = jnp.concatenate(
         [jnp.zeros((1,), theta.dtype), theta.astype(jnp.float32)])
-    return tape.const[None, :] + xterm + theta_pad[tape.theta_idx][None, :]
+    return tape.const[None, :] + xterm + _take_cols(theta_pad[None, :],
+                                                    tape.theta_idx)
 
 
+# Each builder gives a gate's matrix as its entries (g00, g01, g10, g11):
+# complex64 arrays of the angles' shape, or scalars where constant.
 def _mat_h(ang):
-    return jnp.broadcast_to(sv._H, (ang.shape[0], 2, 2))
+    return tuple(np.asarray(sv._H).ravel())
 
 
 def _mat_p(ang):
-    th = ang.astype(jnp.complex64)
-    one, zero = jnp.ones_like(th), jnp.zeros_like(th)
-    return jnp.stack([jnp.stack([one, zero], -1),
-                      jnp.stack([zero, jnp.exp(1j * th)], -1)], -2)
+    return 1.0, 0.0, 0.0, jnp.exp(1j * ang.astype(jnp.complex64))
 
 
 def _mat_ry(ang):
     c = jnp.cos(ang / 2).astype(sv.CDTYPE)
     s = jnp.sin(ang / 2).astype(sv.CDTYPE)
-    return jnp.stack([jnp.stack([c, -s], -1),
-                      jnp.stack([s, c], -1)], -2)
+    return c, -s, s, c
 
 
 def _mat_rz(ang):
     e = jnp.exp(-0.5j * ang.astype(jnp.complex64))
-    zero = jnp.zeros_like(e)
-    return jnp.stack([jnp.stack([e, zero], -1),
-                      jnp.stack([zero, jnp.conj(e)], -1)], -2)
+    return e, 0.0, 0.0, jnp.conj(e)
 
 
 def _mat_x(ang):
-    return jnp.broadcast_to(sv._X, (ang.shape[0], 2, 2))
+    return tuple(np.asarray(sv._X).ravel())
 
 
 _MAT_FNS = (_mat_h, _mat_p, _mat_ry, _mat_rz, _mat_x)
+
+
+def gate_matrix(gid: int, ang: jnp.ndarray) -> jnp.ndarray:
+    """Gate ``gid``'s matrix per angle of ``ang`` (B,) → (B, 2, 2)."""
+    g = [jnp.broadcast_to(x, ang.shape).astype(sv.CDTYPE)
+         for x in _MAT_FNS[gid](ang)]
+    return jnp.stack([jnp.stack(g[:2], -1), jnp.stack(g[2:], -1)], -2)
 
 
 def pair_indices(target, control, n_qubits: int):
     """Index pairs (amp with target bit 0, partner) + control mask.
 
     Returns (idx0, idx1) each (2**n / 2,) int32 and cmask (2**n / 2,) bool —
-    True where the gate acts (control bit set, or no control).
+    True where the gate acts (control bit set, or no control).  The
+    Pallas kernel's pairing metadata; the jnp path needs none.
     """
     half = (1 << n_qubits) // 2
     shift = n_qubits - 1 - target
@@ -274,15 +294,51 @@ def pair_indices(target, control, n_qubits: int):
     return idx0, idx1, cmask
 
 
-def jnp_gate_apply(psi, g, target, control, n_qubits: int):
-    """Fused batched (controlled) 2×2 gate on (B, 2**n) statevectors."""
-    idx0, idx1, cmask = pair_indices(target, control, n_qubits)
-    a0, a1 = psi[:, idx0], psi[:, idx1]
-    n0 = g[:, 0, 0, None] * a0 + g[:, 0, 1, None] * a1
-    n1 = g[:, 1, 0, None] * a0 + g[:, 1, 1, None] * a1
-    n0 = jnp.where(cmask[None, :], n0, a0)
-    n1 = jnp.where(cmask[None, :], n1, a1)
-    return psi.at[:, idx0].set(n0).at[:, idx1].set(n1)
+def _apply_2x2(t: jnp.ndarray, axis: int, g) -> jnp.ndarray:
+    """The gate with entries ``g`` on axis ``axis`` of ``t`` (B, 2, …, 2):
+    static slices of the bit-0 and bit-1 halves, mixed and joined."""
+    a0 = jax.lax.slice_in_dim(t, 0, 1, axis=axis)
+    a1 = jax.lax.slice_in_dim(t, 1, 2, axis=axis)
+    g00, g01, g10, g11 = g
+    return jax.lax.concatenate([g00 * a0 + g01 * a1, g10 * a0 + g11 * a1],
+                               axis)
+
+
+def _swap(t: jnp.ndarray, axis: int) -> jnp.ndarray:
+    """X on axis ``axis``: the halves exchanged, exactly."""
+    return jax.lax.concatenate([jax.lax.slice_in_dim(t, 1, 2, axis=axis),
+                                jax.lax.slice_in_dim(t, 0, 1, axis=axis)],
+                               axis)
+
+
+def _on_target(t: jnp.ndarray, target: int, control: int,
+               fn: Callable) -> jnp.ndarray:
+    """``fn(t, axis)`` on the target's axis of ``t`` (B, 2, …, 2), qubit q
+    on axis 1 + q; with a control, on its control-1 half only, the
+    control-0 half kept as it is."""
+    if control < 0:
+        return fn(t, 1 + target)
+    off = jax.lax.slice_in_dim(t, 0, 1, axis=1 + control)
+    on = jax.lax.slice_in_dim(t, 1, 2, axis=1 + control)
+    return jax.lax.concatenate([off, fn(on, 1 + target)], 1 + control)
+
+
+def jnp_gate_apply(psi, g, target: int, control: int, n_qubits: int):
+    """Batched (controlled) 2×2 gate ``g`` (B, 2, 2) on (B, 2**n)
+    statevectors, as ``run_tape`` applies each gate.
+
+    ``target`` and ``control`` (−1: none) are static ints.  The state is
+    viewed as a (B, 2, …, 2) tensor and the two halves of the target's
+    axis are taken by static slices and joined again: no gather, no
+    scatter.
+    """
+    B = psi.shape[0]
+    col = (B,) + (1,) * n_qubits
+    entries = tuple(g[:, i, j].reshape(col) for i in (0, 1) for j in (0, 1))
+    t = psi.reshape((B,) + (2,) * n_qubits)
+    t = _on_target(t, int(target), int(control),
+                   functools.partial(_apply_2x2, g=entries))
+    return t.reshape(psi.shape)
 
 
 def pallas_gate_apply(psi, g, target, control, n_qubits: int):
@@ -297,20 +353,49 @@ def pallas_gate_apply(psi, g, target, control, n_qubits: int):
 
 def run_tape(tape: GateTape, angles: jnp.ndarray, *,
              gate_apply: Optional[Callable] = None) -> jnp.ndarray:
-    """Replay the tape on |0…0⟩ for a batch → (B, 2**n) complex64."""
-    apply_fn = gate_apply or jnp_gate_apply
-    B = angles.shape[0]
-    psi0 = jnp.zeros((B, 1 << tape.n_qubits), sv.CDTYPE).at[:, 0].set(1.0)
-    xs = (jnp.asarray(tape.gate_id), jnp.asarray(tape.target),
-          jnp.asarray(tape.control), angles.T)
+    """Replay the tape on |0…0⟩ for a batch → (B, 2**n) complex64.
 
-    def step(psi, x):
-        gid, tq, cq, ang = x
-        g = jax.lax.switch(gid, _MAT_FNS, ang)
-        return apply_fn(psi, g, tq, cq, tape.n_qubits), None
+    The replay is unrolled at trace time: each gate's kind, target and
+    control are Python ints read from the tape, so the program is one
+    straight line of gates (no loop, no branch) and only the angles,
+    column g of ``angles`` for gate g, are data.  It is traced once per
+    circuit and batch shape, however often a program replays it.
+    ``gate_apply``, where given, gets each gate as a (B, 2, 2) matrix.
+    """
+    rows = tuple(zip(tape.gate_id.tolist(), tape.target.tolist(),
+                     tape.control.tolist()))
+    return _replay(tape.n_qubits, rows, gate_apply)(angles)
 
-    psi, _ = jax.lax.scan(step, psi0, xs)
-    return psi
+
+@functools.lru_cache(maxsize=None)
+def _replay(n: int, rows: Tuple[Tuple[int, int, int], ...],
+            gate_apply: Optional[Callable]) -> Callable:
+    """The jitted replay of one circuit's gate rows."""
+    basis0 = np.zeros((1,) + (2,) * n, np.complex64)
+    basis0[(0,) * (n + 1)] = 1.0
+
+    @jax.jit
+    def replay(angles):
+        B = angles.shape[0]
+        psi = jnp.broadcast_to(basis0, (B,) + basis0.shape[1:])
+        if gate_apply is not None:
+            psi = psi.reshape(B, 1 << n)
+            for g, (gid, tq, cq) in enumerate(rows):
+                psi = gate_apply(psi, gate_matrix(gid, angles[:, g]), tq, cq,
+                                 n)
+            return psi
+        for g, (gid, tq, cq) in enumerate(rows):
+            if gid == GATE_X:
+                fn = _swap
+            else:
+                ang = jax.lax.slice_in_dim(angles, g, g + 1, axis=1)
+                fn = functools.partial(
+                    _apply_2x2,
+                    g=_MAT_FNS[gid](ang.reshape((B,) + (1,) * n)))
+            psi = _on_target(psi, tq, cq, fn)
+        return psi.reshape(B, 1 << n)
+
+    return replay
 
 
 def tape_probs(cq: CompiledQNN, theta: jnp.ndarray, X: jnp.ndarray, *,

@@ -8,7 +8,8 @@ chip's HBM.  These tests compile, without running:
     32 heads of 64), each lowered through Mosaic (``interpret=False``);
   - the batched LLM stage's round program at those widths over the
     README quickstart task, within one chip's HBM;
-  - the fused round program at 4 qubits with 5 clients.
+  - the fused round program at 4 qubits with 5 clients, whose tape
+    replays hold no loop.
 
 The two programs' compiled texts also carry the program's layer scopes
 (``repro.telemetry``), as the chip's compiler leaves them.
@@ -152,3 +153,6 @@ def test_fused_rounds_compile(one_chip, task):
     assert set(tel.ROUND_SCOPES) - {tel.QFL_SELECT, tel.QFL_GATHER} \
         <= scopes(text)
     assert unscoped_share(text) < 0.1
+    # the tape replays as straight-line code: no loop under its scope
+    assert not [line for line in text.splitlines()
+                if " while(" in line and tel.TAPE_REPLAY in line]
